@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 from typing import Dict, Optional
 
 import numpy as np
@@ -35,13 +36,24 @@ def _resolve_jobs(jobs: Optional[int], n_tasks: int) -> int:
     return max(1, min(jobs, n_tasks))
 
 
+def _jax_backend_live() -> bool:
+    """True once this process has initialised a JAX backend."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge  # JAX has no public query for this
+    return xla_bridge.backends_are_initialized()
+
+
 def _pmap(fn, args, jobs, weights=None):
     """Order-preserving map over a fork pool (sequential when jobs==1).
 
     ``weights`` (heavier = dispatched first) avoids a long task landing
     last on an otherwise-drained pool; results come back in input order.
+    Once this process has initialised a JAX backend the map runs
+    sequentially: a forked child would inherit the live runtime, and on a
+    TPU host the chip, which belongs to one process at a time.
     """
-    if jobs == 1:
+    if jobs == 1 or _jax_backend_live():
         return [fn(a) for a in args]
     import multiprocessing as mp
     try:

@@ -1,8 +1,9 @@
 """jax target: drive the generated CU through the real Pallas kernel layer.
 
-The decoupled arrays live on device as ``(n, 1)`` int32 tables; the
-generated CU (:func:`repro.codegen.emit.compile_mode` in ``cu-jax`` mode)
-runs as a host-side generator that *yields* an array name whenever its
+The decoupled arrays live on device as lane-dense int32 tables
+(:class:`DeviceTable`); the generated CU
+(:func:`repro.codegen.emit.compile_mode` in ``cu-jax`` mode) runs as a
+host-side generator that *yields* an array name whenever its
 load-value buffer runs dry.  On each yield the driver
 
 1. **flushes** every store value the CU has produced for that array —
@@ -33,6 +34,8 @@ from __future__ import annotations
 from collections import deque
 from typing import Any, Dict, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..resilience import faults
@@ -55,16 +58,77 @@ def _check_i32(name: str, arr: np.ndarray) -> None:
             f"jax target: {name} holds values outside int32 range")
 
 
+#: lanes of one TPU vector register row — the width of a device-table row
+LANES = 128
+
+
+@jax.jit
+def _pick(rows: jax.Array, idx: jax.Array) -> jax.Array:
+    """Element ``idx[k]`` out of its gathered row ``rows[k]``."""
+    return jnp.take_along_axis(rows, (idx & (LANES - 1))[:, None], 1)[:, 0]
+
+
+@jax.jit
+def _spread(idx: jax.Array, vals: jax.Array) -> jax.Array:
+    """One row per request: ``vals[k]`` at ``idx[k]``'s lane, zeros
+    elsewhere (adding a zero leaves an int32 element bit-unchanged)."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], LANES), 1)
+    return jnp.where(lanes == (idx & (LANES - 1))[:, None], vals[:, None], 0)
+
+
+class DeviceTable:
+    """A flat int32 array on device, lane-dense: element ``i`` lives at
+    row ``i // 128``, lane ``i % 128`` of a ``(rows, 128)`` table.
+
+    A TPU DMA moves whole ``(8, 128)`` tiles, so the kernels cannot copy
+    a one-lane row of an ``(n, 1)`` table, and padding every element to a
+    full row would cost 128x the memory.  Packing 128 elements per row
+    keeps the footprint of the flat array; a gather fetches the element's
+    row and picks the lane, a scatter-add adds a row that is zero outside
+    the element's lane — exact in int32, so results stay bit-identical.
+    Indices are flat element indices; ``-1`` is poison, as in the kernels.
+    """
+
+    def __init__(self, flat: np.ndarray, block_n: int, interpret):
+        self.n = len(flat)
+        rows = -(-max(self.n, 1) // LANES)
+        rows += -rows % 8                 # whole (8, 128) int32 tiles
+        buf = np.zeros(rows * LANES, np.int32)
+        buf[:self.n] = flat
+        self.table = jnp.asarray(buf.reshape(rows, LANES))
+        self.block_n = block_n
+        self.interpret = interpret
+
+    def gather(self, idx: np.ndarray) -> jax.Array:
+        """``flat[idx]`` with poisoned (``-1``) requests reading 0."""
+        from ..kernels.spec_gather import spec_gather
+        rows = spec_gather(self.table, jnp.asarray(idx // LANES),
+                           block_d=LANES, block_n=self.block_n,
+                           interpret=self.interpret)
+        return _pick(rows, jnp.asarray(idx))
+
+    def scatter_add(self, idx: np.ndarray, vals) -> None:
+        """``flat[idx] += vals`` with poisoned requests dropped."""
+        from ..kernels.spec_scatter import spec_scatter_add
+        self.table = spec_scatter_add(
+            self.table, jnp.asarray(idx // LANES),
+            _spread(jnp.asarray(idx), jnp.asarray(vals, jnp.int32)),
+            block_d=LANES, block_n=self.block_n, interpret=self.interpret)
+
+    def to_numpy(self) -> np.ndarray:
+        """The flat array, back on the host."""
+        return np.asarray(self.table).reshape(-1)[:self.n]
+
+
 class _ArrayDriver:
     """Epoch scheduler for one decoupled array."""
 
     def __init__(self, name: str, mem: np.ndarray, streams: Streams,
                  block_n: int, interpret):
-        import jax.numpy as jnp
         self.name = name
         self.dtype = mem.dtype
         self.hi = len(mem) - 1
-        self.table = jnp.asarray(mem.astype(np.int32).reshape(-1, 1))
+        self.table = DeviceTable(mem.astype(np.int32), block_n, interpret)
         # shadow replica of the device table, kept only when the armed
         # plan can silently corrupt data (see faults.CORRUPTION_SITES):
         # exact by induction (only these flushes mutate the table), so
@@ -79,7 +143,6 @@ class _ArrayDriver:
         self.lp = 0          # next unconsumed load index
         self.fp = 0          # flushed store count
         self.block_n = block_n
-        self.interpret = interpret
         self.gather_calls = 0
         self.scatter_calls = 0
 
@@ -122,22 +185,14 @@ class _ArrayDriver:
         del produced[:]
 
     def _scatter(self, idx_list: list, val_list: list) -> None:
-        import jax.numpy as jnp
-        from ..kernels.spec_gather import spec_gather
-        from ..kernels.spec_scatter import spec_scatter_add
         n = len(idx_list)
         b = bucket(n, self.block_n)
         idx = np.full(b, -1, np.int32)
         idx[:n] = idx_list
-        vals = np.zeros((b, 1), np.int32)
-        vals[:n, 0] = val_list
-        jidx = jnp.asarray(idx)
-        cur = spec_gather(self.table, jidx, block_d=1, block_n=self.block_n,
-                          interpret=self.interpret)
-        delta = jnp.where(jidx[:, None] >= 0, jnp.asarray(vals) - cur, 0)
-        self.table = spec_scatter_add(self.table, jidx, delta, block_d=1,
-                                      block_n=self.block_n,
-                                      interpret=self.interpret)
+        vals = np.zeros(b, np.int32)
+        vals[:n] = val_list
+        cur = self.table.gather(idx)
+        self.table.scatter_add(idx, jnp.where(idx >= 0, vals - cur, 0))
         self.gather_calls += 1
         self.scatter_calls += 1
         if self.shadow is not None:
@@ -150,8 +205,6 @@ class _ArrayDriver:
     # -- load refill ---------------------------------------------------------
     def refill(self, buf: deque) -> int:
         """Gather the next epoch of load values into ``buf``."""
-        import jax.numpy as jnp
-        from ..kernels.spec_gather import spec_gather
         faults.inject("codegen.jax.refill")
         lds = self.ld_clamped
         if self.lp >= len(lds):
@@ -169,10 +222,9 @@ class _ArrayDriver:
         b = bucket(n, self.block_n)
         idx = np.full(b, -1, np.int32)
         idx[:n] = take
-        vals = spec_gather(self.table, jnp.asarray(idx), block_d=1,
-                           block_n=self.block_n, interpret=self.interpret)
+        vals = self.table.gather(idx)
         self.gather_calls += 1
-        got = np.asarray(vals[:n, 0])
+        got = np.asarray(vals[:n])
         if self.shadow is not None:
             exp = self.shadow[np.asarray(take, dtype=np.int64)]
             if not np.array_equal(got, exp):
@@ -230,7 +282,7 @@ def run_jax(compiled, memory: Dict[str, np.ndarray],
     for a in dec:
         drv = drivers[a]
         if drv.shadow is not None:
-            tab = np.asarray(drv.table[:, 0])
+            tab = drv.table.to_numpy()
             if not np.array_equal(tab, drv.shadow):
                 raise FaultDetected(
                     "codegen.jax.commit",
@@ -241,7 +293,7 @@ def run_jax(compiled, memory: Dict[str, np.ndarray],
     for a, mirror in stats.pop("locals", {}).items():
         memory[a][:] = mirror
     for a in dec:
-        tab = np.asarray(drivers[a].table[:, 0]).astype(memory[a].dtype)
+        tab = drivers[a].table.to_numpy().astype(memory[a].dtype)
         memory[a][:] = tab
     stats["gather_calls"] = sum(d.gather_calls for d in drivers.values())
     stats["scatter_calls"] = sum(d.scatter_calls for d in drivers.values())

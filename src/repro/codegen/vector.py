@@ -35,8 +35,8 @@ Two drivers implement the memory operations:
   working copies (any dtype), written back only after the whole run
   succeeds; forwarded commits use the ``np.add.reduceat`` combine.
 * :class:`_JaxVectorDriver` — the decoupled arrays live on device as
-  **one fused** ``(n_total, 1)`` int32 table behind per-array base
-  offsets, so every epoch is **one** ``spec_gather`` plus at most one
+  **one fused** lane-dense int32 table behind per-array base offsets,
+  so every epoch is **one** ``spec_gather`` plus at most one
   WAW/RAW-resolved ``spec_scatter_add`` serving *all* arrays: poisoned
   slots are ``-1`` indices (the kernels' pad-with-poison path),
   superseded WAW slots are masked to ``-1`` instead of splitting the
@@ -691,17 +691,17 @@ class _NumpyVectorDriver(_VectorDriver):
 class _JaxVectorDriver(_VectorDriver):
     """Epochs against one fused device int32 table (Pallas kernels).
 
-    Every decoupled array occupies a contiguous row range of a single
-    ``(n_total, 1)`` table at a per-array base offset, so one
-    ``spec_gather`` serves every load of an epoch and one
-    ``spec_scatter_add`` serves every store — kernel-call counts are per
-    *epoch*, not per array.
+    Every decoupled array occupies a contiguous element range of a single
+    lane-dense :class:`~repro.codegen.jax_backend.DeviceTable` at a
+    per-array base offset, so one ``spec_gather`` serves every load of an
+    epoch and one ``spec_scatter_add`` serves every store — kernel-call
+    counts are per *epoch*, not per array.
     """
 
     def __init__(self, loops, streams, memory, arrays, block_n, interpret,
                  forward=True):
         super().__init__(loops, streams, memory, arrays, forward)
-        import jax.numpy as jnp
+        from .jax_backend import DeviceTable
         self.base: Dict[str, int] = {}
         off = 0
         parts = []
@@ -709,35 +709,28 @@ class _JaxVectorDriver(_VectorDriver):
             self.base[a] = off
             off += len(memory[a])
             parts.append(memory[a].astype(np.int64))
-        self.n_total = off
         self.mirror = (np.concatenate(parts) if parts
                        else np.zeros(0, np.int64))
-        self.table = jnp.asarray(
-            self.mirror.astype(np.int32).reshape(-1, 1))
+        self.table = DeviceTable(self.mirror.astype(np.int32),
+                                 max(8, block_n), interpret)
         self.block_n = block_n
-        self.interpret = interpret
         self.gather_calls = 0
         self.scatter_calls = 0
 
     def _gather_all(self, req: Dict[str, np.ndarray]
                     ) -> Dict[str, np.ndarray]:
         """One fused ``spec_gather`` covering every array of the epoch."""
-        import jax.numpy as jnp
-        from ..kernels.spec_gather import spec_gather
         if not req:
             return {}
         names = sorted(req)
         gidx = np.concatenate(
             [self.base[a] + req[a] for a in names])
         n = len(gidx)
-        b = bucket(n, self.block_n)
-        pad = np.full(b, -1, np.int32)
+        pad = np.full(bucket(n, self.block_n), -1, np.int32)
         pad[:n] = gidx
-        vals = spec_gather(self.table, jnp.asarray(pad), block_d=1,
-                           block_n=min(max(8, self.block_n), b),
-                           interpret=self.interpret)
+        vals = self.table.gather(pad)
         self.gather_calls += 1
-        flat = np.asarray(vals[:n, 0]).astype(np.int64)
+        flat = np.asarray(vals[:n]).astype(np.int64)
         if faults.corrupting():
             # the host mirror is exact by induction — a gather that
             # disagrees with it returned corrupted rows; catch it before
@@ -766,8 +759,6 @@ class _JaxVectorDriver(_VectorDriver):
         (:func:`repro.codegen.epochs.combine_runs`).  All rows land in a
         single kernel call against the fused table.
         """
-        import jax.numpy as jnp
-        from ..kernels.spec_scatter import spec_scatter_add
         rows_i: List[np.ndarray] = []
         rows_d: List[np.ndarray] = []
         post = []  # mirror updates applied only after the device commit
@@ -813,11 +804,9 @@ class _JaxVectorDriver(_VectorDriver):
         b = bucket(n, self.block_n)
         idx = np.full(b, -1, np.int32)
         idx[:n] = gidx
-        delta = np.zeros((b, 1), np.int32)
-        delta[:n, 0] = gdel
-        self.table = spec_scatter_add(
-            self.table, jnp.asarray(idx), jnp.asarray(delta), block_d=1,
-            block_n=min(max(8, self.block_n), b), interpret=self.interpret)
+        delta = np.zeros(b, np.int32)
+        delta[:n] = gdel
+        self.table.scatter_add(idx, delta)
         self.scatter_calls += 1
         for kind, gi, v in post:
             if kind == "set":
@@ -829,7 +818,7 @@ class _JaxVectorDriver(_VectorDriver):
         """Compare the fused device table against the host mirror."""
         if not faults.corrupting():
             return
-        tab = np.asarray(self.table[:, 0]).astype(np.int64)
+        tab = self.table.to_numpy().astype(np.int64)
         if not np.array_equal(tab, self.mirror):
             raise FaultDetected(
                 "codegen.vector.commit",
@@ -838,7 +827,7 @@ class _JaxVectorDriver(_VectorDriver):
 
     def finalize(self, memory: Dict[str, np.ndarray]) -> None:
         """Split the fused table back into the caller's arrays."""
-        tab = np.asarray(self.table[:, 0])
+        tab = self.table.to_numpy()
         for a in self.arrays:
             o = self.base[a]
             memory[a][:] = tab[o:o + len(memory[a])].astype(memory[a].dtype)

@@ -1,3 +1,4 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas TPU kernels: the paper's speculative data movement
+# (spec_gather.py, spec_scatter.py) plus attention and grouped-matmul
+# kernels; ref.py holds the pure-jnp oracles the tests compare against,
+# backend.py decides compiled vs interpret mode for every kernel call.

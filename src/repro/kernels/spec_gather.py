@@ -13,12 +13,17 @@ The paper's DAE template mapped onto the TPU memory system:
 Block layout: grid ``(n // block_n, d // block_d)``; each step gathers a
 ``(block_n, block_d)`` tile.  The table stays un-blocked in ``ANY`` memory
 space and the scalar-prefetched index drives a *burst* of ``block_n``
-row-slice DMAs into a VMEM scratch tile (all started, then all awaited, so
-the copies overlap), after which the poison mask is applied per-row inside
-the tile.  The feature dim is tiled to keep the VMEM working set bounded
-for wide rows.  ``n`` not divisible by ``block_n`` is handled by padding
-the index vector with poison (``-1``) — padded rows fetch row 0 and mask
-to zero, and the pad is sliced off the output.
+DMAs into a VMEM scratch (all started, then all awaited, so the copies
+overlap), after which each request's row is picked out and the poison
+mask applied.  A TPU DMA moves whole ``(8, 128)`` tiles of a 32-bit table
+(``(16, 128)`` for 16-bit dtypes), so a single table row cannot be its
+own copy: each request fetches the aligned *row block* of ``sub`` rows
+that holds its row (:func:`sublanes`) and the kernel selects the row
+inside VMEM.  Tables whose row count is not a multiple of ``sub`` are
+padded.  The feature dim is tiled to keep the VMEM working set bounded for
+wide rows.  ``n`` not divisible by ``block_n`` is handled by padding the
+index vector with poison (``-1``) — padded rows fetch row 0 and mask to
+zero, and the pad is sliced off the output.
 
 Ragged-``n`` contract with the codegen backend: ``block_n`` is clamped to
 ``min(block_n, n)`` below, so a caller whose batch is smaller than its
@@ -40,26 +45,45 @@ from ..resilience import faults
 from .backend import resolve_interpret
 
 
-def _kernel(idx_ref, table_ref, out_ref, scratch, sems, *, block_n, block_d):
+def sublanes(dtype) -> int:
+    """Rows in one native TPU tile of ``dtype``: 8 for 32-bit, 16 for
+    16-bit, 32 for 8-bit — the granularity of a row-block DMA."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _kernel(idx_ref, table_ref, out_ref, blocks, sems, *, block_n, block_d,
+            sub):
     nb = pl.program_id(0)
     j = pl.program_id(1)
     base = nb * block_n
-    # burst: start all row DMAs, then wait — copies overlap in the DMA
-    # engine (the multi-request window of the paper's DU)
+    cols = pl.ds(pl.multiple_of(j * block_d, block_d), block_d)
+    # burst: start all row-block DMAs, then wait — copies overlap in the
+    # DMA engine (the multi-request window of the paper's DU)
     dmas = []
     for r in range(block_n):
         row = jnp.maximum(idx_ref[base + r], 0)
-        dma = pltpu.make_async_copy(
-            table_ref.at[row, pl.ds(j * block_d, block_d)],
-            scratch.at[r], sems.at[r])
+        start = pl.multiple_of(row // sub * sub, sub)
+        dma = pltpu.make_async_copy(table_ref.at[pl.ds(start, sub), cols],
+                                    blocks.at[r], sems.at[r])
         dma.start()
         dmas.append(dma)
     for dma in dmas:
         dma.wait()
-    rows = jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0) + base
-    poison = (idx_ref[rows] < 0)[:, None]
-    out_ref[...] = jnp.where(poison, jnp.zeros_like(scratch[...]),
-                             scratch[...])
+    # pick each request's row out of its block: a max over the block with
+    # every other row masked to the dtype's least value is exact for every
+    # value (``-0.0`` included) and needs no dynamic sublane addressing,
+    # which packed 16-bit tiles do not support
+    dt = blocks.dtype
+    least = (-jnp.inf if jnp.issubdtype(dt, jnp.floating)
+             else jnp.iinfo(dt).min)
+    block_rows = jax.lax.broadcasted_iota(jnp.int32, (sub, block_d), 0)
+    for r in range(block_n):
+        raw = idx_ref[base + r]
+        hit = block_rows == jnp.maximum(raw, 0) % sub
+        got = jnp.max(jnp.where(hit, blocks[r], least), axis=0,
+                      keepdims=True)
+        out_ref[pl.ds(r, 1), :] = jnp.where(raw < 0, jnp.zeros_like(got),
+                                            got)
 
 
 def spec_gather(table: jax.Array, idx: jax.Array, *, block_d: int = 512,
@@ -96,8 +120,11 @@ def _spec_gather(table: jax.Array, idx: jax.Array, *, block_d: int,
     v, d = table.shape
     bd = min(block_d, d)
     bn = min(block_n, n)
+    sub = sublanes(table.dtype)
     assert d % bd == 0, f"feature dim {d} not divisible by block {bd}"
 
+    if v % sub:
+        table = jnp.pad(table, ((0, -v % sub), (0, 0)))
     pad = (-n) % bn
     if pad:
         idx = jnp.concatenate([idx, jnp.full((pad,), -1, idx.dtype)])
@@ -106,13 +133,13 @@ def _spec_gather(table: jax.Array, idx: jax.Array, *, block_d: int,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(np_ // bn, d // bd),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((bn, bd), lambda i, j, idx_ref: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bn, bd), table.dtype),
+        scratch_shapes=[pltpu.VMEM((bn, sub, bd), table.dtype),
                         pltpu.SemaphoreType.DMA((bn,))],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, block_n=bn, block_d=bd),
+        functools.partial(_kernel, block_n=bn, block_d=bd, sub=sub),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((np_, d), table.dtype),
         interpret=interpret,

@@ -9,12 +9,16 @@ row 0 for the speculative fetch and contribute zero.
 Implementation: grid ``(d // block_d, n // block_n)`` with the request dim
 fast; each step handles a *block* of ``block_n`` destination-sorted
 requests.  The table (aliased as the output) stays un-blocked in ``ANY``
-memory space; per request the kernel DMAs the destination row-slice into a
-VMEM row buffer, accumulates the (poison-masked) contribution, and DMAs it
-back — the scalar-prefetched index drives the row selection, and the
-read-modify-write chain through VMEM keeps same-row runs of the sorted
-requests coherent.  ``n`` not divisible by ``block_n`` pads the request
-vector with poison (contributes nothing, by construction).
+memory space; per request the kernel DMAs the aligned row block holding
+the destination row (``sub`` rows, one native tile —
+:func:`repro.kernels.spec_gather.sublanes`; a TPU DMA cannot move a single
+row of a tiled table) into VMEM, adds the (poison-masked) contribution to
+that one row, and DMAs the block back — the scalar-prefetched index drives
+the row selection, and the read-modify-write chain through VMEM keeps
+same-block runs of the sorted requests coherent.  Rows the request does
+not address are written back bit-unchanged.  Tables whose row count is not
+a multiple of ``sub`` are padded.  ``n`` not divisible by ``block_n`` pads
+the request vector with poison (contributes nothing, by construction).
 
 Ragged-``n`` contract with the codegen backend: ``block_n`` is clamped to
 ``min(block_n, n)`` below, but the epoch drivers
@@ -35,25 +39,28 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..resilience import faults
 from .backend import resolve_interpret
+from .spec_gather import sublanes
 
 
-def _kernel(idx_ref, vals_ref, table_ref, out_ref, rowbuf, sem, *,
-            block_n, block_d):
+def _kernel(idx_ref, vals_ref, table_ref, out_ref, blk, sem, *,
+            block_n, block_d, sub):
     j = pl.program_id(0)
     nb = pl.program_id(1)
     base = nb * block_n
+    cols = pl.ds(pl.multiple_of(j * block_d, block_d), block_d)
+    block_rows = jax.lax.broadcasted_iota(jnp.int32, (sub, block_d), 0)
     for r in range(block_n):
         raw = idx_ref[base + r]
         row = jnp.maximum(raw, 0)
-        poison = raw < 0
-        rd = pltpu.make_async_copy(
-            out_ref.at[row, pl.ds(j * block_d, block_d)], rowbuf, sem)
+        start = pl.multiple_of(row // sub * sub, sub)
+        window = out_ref.at[pl.ds(start, sub), cols]
+        rd = pltpu.make_async_copy(window, blk, sem)
         rd.start()
         rd.wait()
-        contrib = jnp.where(poison, jnp.zeros_like(vals_ref[r]), vals_ref[r])
-        rowbuf[...] = rowbuf[...] + contrib
-        wr = pltpu.make_async_copy(
-            rowbuf, out_ref.at[row, pl.ds(j * block_d, block_d)], sem)
+        hit = (block_rows == row - start) & (raw >= 0)
+        cur = blk[...]
+        blk[...] = jnp.where(hit, cur + vals_ref[pl.ds(r, 1), :], cur)
+        wr = pltpu.make_async_copy(blk, window, sem)
         wr.start()
         wr.wait()
 
@@ -97,12 +104,16 @@ def _spec_scatter_add(table: jax.Array, idx: jax.Array, values: jax.Array, *,
     v, d = table.shape
     bd = min(block_d, d)
     bn = min(block_n, n)
+    sub = sublanes(table.dtype)
     assert d % bd == 0
 
     order = jnp.argsort(idx)
     idx = idx[order]
     values = values[order]
 
+    vp = v + (-v % sub)
+    if vp != v:
+        table = jnp.pad(table, ((0, vp - v), (0, 0)))
     pad = (-n) % bn
     if pad:
         idx = jnp.concatenate([idx, jnp.full((pad,), -1, idx.dtype)])
@@ -115,17 +126,18 @@ def _spec_scatter_add(table: jax.Array, idx: jax.Array, values: jax.Array, *,
         grid=(d // bd, np_ // bn),
         in_specs=[
             pl.BlockSpec((bn, bd), lambda j, i, idx_ref: (i, j)),  # values
-            pl.BlockSpec(memory_space=pltpu.ANY),                  # table
+            pl.BlockSpec(memory_space=pl.ANY),                     # table
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.VMEM((bd,), table.dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((sub, bd), table.dtype),
                         pltpu.SemaphoreType.DMA(())],
     )
-    return pl.pallas_call(
-        functools.partial(_kernel, block_n=bn, block_d=bd),
+    out = pl.pallas_call(
+        functools.partial(_kernel, block_n=bn, block_d=bd, sub=sub),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((v, d), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((vp, d), table.dtype),
         input_output_aliases={2: 0},  # table aliases the output (index
                                       # counts the scalar-prefetch operand)
         interpret=interpret,
     )(idx, values, table)
+    return out[:v] if vp != v else out

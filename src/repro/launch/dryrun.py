@@ -140,7 +140,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
             k: NamedSharding(mesh, P(*([None] * len(v.shape))))
             for k, v in ins.items()}
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if info["kind"] == "train":
             init_state, train_step, opt_name = make_train_step(model)
             state_shapes = jax.eval_shape(

@@ -56,8 +56,12 @@ def run_matrix(mesh_kinds=("single", "multi"), only=None,
                 print(f"[run ] {name} × {shape} × {mesh} ...",
                       flush=True)
                 try:
+                    # the dry run is a CPU tool: on a TPU host a child
+                    # left to JAX's default would take the chip
                     p = subprocess.run(cmd, capture_output=True, text=True,
-                                       timeout=timeout)
+                                       timeout=timeout,
+                                       env={**os.environ,
+                                            "JAX_PLATFORMS": "cpu"})
                     ok = p.returncode == 0 and os.path.exists(out)
                 except subprocess.TimeoutExpired:
                     ok, p = False, None

@@ -27,18 +27,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..configs.base import ArchConfig, param_count
 
 
-def auto_axis_types(n_axes: int) -> dict:
-    """``axis_types`` kwarg for ``jax.make_mesh`` — empty on jax versions
-    that predate ``jax.sharding.AxisType`` (where Auto is the only mode)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:

@@ -39,6 +39,12 @@ class Model(NamedTuple):
 
     # ------------------------------------------------------------------ init
     def init(self, key: jax.Array) -> Dict:
+        """Random parameters, drawn as one jitted program: op by op, every
+        weight's float32 draw would be live at once before its cast (about
+        3.8 GB per expert weight at Kimi's full widths)."""
+        return jax.jit(self._init)(key)
+
+    def _init(self, key: jax.Array) -> Dict:
         cfg = self.cfg
         d, v = cfg.d_model, cfg.vocab
         dt = cfg.jdtype
@@ -48,8 +54,11 @@ class Model(NamedTuple):
             return jnp.ones(shape, dt)
 
         def dense(key, shape, scale=0.02):
-            return (jax.random.normal(key, shape, jnp.float32) * scale
-                    ).astype(dt)
+            # the barrier stops XLA folding ``scale`` into the sampler's own
+            # constants, so the draws match op-by-op execution bit for bit
+            z = jax.lax.optimization_barrier(
+                jax.random.normal(key, shape, jnp.float32))
+            return (z * scale).astype(dt)
 
         def sublayer_params(key, kind):
             ks = jax.random.split(key, 12)

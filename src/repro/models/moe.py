@@ -47,22 +47,6 @@ from ..kernels.spec_scatter import spec_scatter_add
 from .sharding import _current_mesh
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map: ``jax.shard_map`` (with ``check_vma``)
-    on new jax, ``jax.experimental.shard_map`` (``check_rep``) on older
-    releases such as the pinned CI one."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:  # pre-check_vma spelling of the same knob
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def round_capacity(n_tokens: int, n_experts: int, top_k: int,
                    factor: float, multiple: int = 8) -> int:
     cap = int(factor * n_tokens * top_k / n_experts) + 1
@@ -87,6 +71,28 @@ def spec_dispatch_indices(gates: jax.Array, experts: jax.Array,
     poison = pos >= capacity
     slot = jnp.where(poison, -1, slot)
     return slot, jnp.where(poison, 0.0, gates)
+
+
+def _combine(h: jax.Array, slot: jax.Array, gates: jax.Array, *,
+             kernel: bool) -> jax.Array:
+    """Combine: gather each request's expert output row back (poisoned
+    slots read zero) and sum a token's top-k rows weighted by ``gates``.
+
+    The gathered rows pass an optimisation barrier on both paths, so XLA
+    cannot fuse the lax gather into the weighted sum — a fusion the
+    opaque kernel call never gets, and one that lets the compiler round
+    the sum differently (a fused multiply-add), breaking bit-identity.
+    """
+    n, top_k = gates.shape
+    if kernel:
+        gathered = spec_gather(h, slot)
+    else:
+        gathered = jnp.where((slot < 0)[:, None],
+                             jnp.zeros((1, h.shape[1]), h.dtype),
+                             h[jnp.maximum(slot, 0)])
+    gathered = jax.lax.optimization_barrier(gathered)
+    return (gathered.reshape(n, top_k, -1)
+            * gates[..., None].astype(h.dtype)).sum(axis=1)
 
 
 def moe_spec(params: Dict, x: jax.Array, *, n_experts: int, top_k: int,
@@ -176,14 +182,8 @@ def _moe_spec_ep(params: Dict, x: jax.Array, *, n_experts: int, top_k: int,
         h = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, wd)
         h = h.reshape(e_loc * cap, d)
 
-        if kernel:
-            gathered = spec_gather(h, slot)
-        else:
-            gathered = jnp.where(poison[:, None], jnp.zeros((1, d), h.dtype),
-                                 h[safe])
         gg = jnp.where(poison.reshape(-1, top_k), 0.0, gates)
-        out = (gathered.reshape(n_loc, top_k, d)
-               * gg[..., None].astype(h.dtype)).sum(axis=1)
+        out = _combine(h, slot, gg, kernel=kernel)
         # a request commits on exactly one model shard (its expert's home)
         # unless it lost the capacity race there, so summing commits over
         # ``model`` counts each surviving request once — globally identical
@@ -192,8 +192,8 @@ def _moe_spec_ep(params: Dict, x: jax.Array, *, n_experts: int, top_k: int,
         poisoned = jax.lax.psum(n_loc * top_k - committed, dp)
         return jax.lax.psum(out, "model"), poisoned.astype(jnp.int32)
 
-    out, poisoned = _shard_map(
-        local_fn, mesh=mesh,
+    out, poisoned = jax.shard_map(
+        local_fn, mesh=mesh, check_vma=False,
         in_specs=(P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None),
                   P(dp, None)),
@@ -242,21 +242,15 @@ def _moe_spec_tp(params: Dict, x: jax.Array, *, n_experts: int, top_k: int,
         h = jax.lax.psum(h, "model")                 # f-partial sums
         h = h.reshape(n_experts * cap, d)
 
-        if kernel:
-            gathered = spec_gather(h, flat)
-        else:
-            gathered = jnp.where((flat < 0)[:, None],
-                                 jnp.zeros((1, d), h.dtype), h[safe])
-        out = (gathered.reshape(n_loc, top_k, d)
-               * gates[..., None].astype(h.dtype)).sum(axis=1)
+        out = _combine(h, flat, gates, kernel=kernel)
         # every model shard dispatches the same replicated tokens, so the
         # local poison count is already the per-dp-shard total — sum over
         # the data axes only (summing over ``model`` would multiply-count).
         poisoned = jax.lax.psum(n_loc * top_k - jnp.sum(flat >= 0), dp)
         return out, poisoned.astype(jnp.int32)
 
-    out, poisoned = _shard_map(
-        local_fn, mesh=mesh,
+    out, poisoned = jax.shard_map(
+        local_fn, mesh=mesh, check_vma=False,
         in_specs=(P(None, None), P(None, None, "model"),
                   P(None, None, "model"), P(None, "model", None),
                   P(dp, None)),
@@ -308,13 +302,7 @@ def _moe_spec_flat(params: Dict, x: jax.Array, *, n_experts: int,
     h = h.reshape(n_experts * capacity, d)
 
     # --- combine: gather back, poisoned slots contribute zero -------------
-    if kernel:
-        gathered = spec_gather(h, flat_slot)
-    else:
-        gathered = jnp.where((flat_slot < 0)[:, None],
-                             jnp.zeros((1, d), h.dtype), h[safe])
-    out = (gathered.reshape(n, top_k, d)
-           * gates[..., None].astype(h.dtype)).sum(axis=1)
+    out = _combine(h, flat_slot, gates, kernel=kernel)
 
     if "shared_w_gate" in params:
         from .layers import swiglu

@@ -4,7 +4,7 @@ Without these, GSPMD propagation through reshape/scan picks degenerate
 layouts — e.g. sharding the *contracted* head_dim of MQA attention, turning
 every score block into an all-reduce (EXPERIMENTS.md §Perf records the
 before/after).  ``constrain(x, ...)`` applies a PartitionSpec only when a
-mesh is active and the dims divide; the pseudo-axis ``"dp"`` expands to
+mesh is set (``jax.set_mesh``) and the dims divide; the pseudo-axis ``"dp"`` expands to
 ``("pod", "data")`` on multi-pod meshes.  On meshless CPU smoke runs every
 constraint is a no-op.
 """
@@ -19,20 +19,9 @@ Axis = Union[None, str, Tuple[str, ...]]
 
 
 def _current_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    try:  # physical mesh context (`with mesh:`)
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def constrain(x: jax.Array, *axes: Axis) -> jax.Array:

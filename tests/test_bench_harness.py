@@ -406,3 +406,64 @@ def test_no_bytecode_tracked_and_pycache_ignored():
         ["git", "check-ignore", "-q", "benchmarks/__pycache__/stale.pyc"],
         cwd=root).returncode == 0
     assert ignored, "benchmarks/__pycache__ is not git-ignored"
+
+
+@pytest.mark.parametrize("path", [".jax_cache/entry", "chiprun_out/log"])
+def test_runtime_dirs_git_ignored(path):
+    """The compile cache and chip-run output are made at run time and
+    must never be committed."""
+    import pathlib
+    import shutil
+    import subprocess
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    if shutil.which("git") is None or not (root / ".git").exists():
+        pytest.skip("not a git checkout")
+    assert subprocess.run(["git", "check-ignore", "-q", path],
+                          cwd=root).returncode == 0
+
+
+# ---------------------------------------------------------------------------
+# entry-point process and cache hygiene
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir(tmp_path, monkeypatch, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and no other directory is set;
+    without it the cache lives at the fixed ``<root>/.jax_cache``."""
+    import jax
+
+    from repro.launch.compile_cache import use_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        got = use_compile_cache(str(tmp_path))
+        if env_dir is None:
+            want = str(tmp_path / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            want = str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == prev
+        assert got == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_pmap_sequential_once_jax_backend_live(monkeypatch):
+    """A fork pool must not start from a process that holds a JAX
+    runtime (on a TPU host, the chip): the map runs in-process."""
+    import os
+
+    monkeypatch.setattr(dae_table1, "_jax_backend_live", lambda: True)
+    pids = dae_table1._pmap(_pid, [0, 1, 2], jobs=2)
+    assert pids == [os.getpid()] * 3
+
+
+def _pid(_):
+    import os
+    return os.getpid()

@@ -65,8 +65,8 @@ def test_param_spec_rules():
     if jax.device_count() < 4:
         pytest.skip("needs XLA_FLAGS=--xla_force_host_platform_device_count")
     from repro.launch.mesh import param_spec
-    from repro.launch.mesh import auto_axis_types
-    mesh = jax.make_mesh((2, 2), ("data", "model"), **auto_axis_types(2))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = get("kimi_k2_1t_a32b")
     # experts: EP over model when divisible
     s = param_spec("groups/s1_moe/w_gate", (384, 7168, 2048), cfg, mesh,

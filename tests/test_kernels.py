@@ -40,12 +40,30 @@ def _arr(shape, dtype=np.float32):
 
 @pytest.mark.parametrize("v,d,n,bd", [(32, 128, 16, 64), (8, 256, 40, 256),
                                       (64, 512, 7, 128), (4, 128, 1, 128)])
-@pytest.mark.parametrize("dtype", [np.float32, np.int32])
+@pytest.mark.parametrize("dtype", [np.float32, np.int32, jnp.bfloat16])
 def test_spec_gather_sweep(v, d, n, bd, dtype):
     table = _arr((v, d)).astype(dtype)
     idx = jnp.asarray(RNG.integers(-3, v, n).astype(np.int32))
     got = spec_gather(table, idx, block_d=bd)
     np.testing.assert_allclose(got, ref.spec_gather(table, idx), atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_spec_gather_exact_bits(dtype):
+    """The kernel picks a row out of its fetched row block; the pick must
+    return every value bit for bit — signed zeros, infinities and NaN
+    included — whatever the other rows of the block hold."""
+    special = np.array([-0.0, 0.0, np.inf, -np.inf, np.nan, -1.5, 3.0,
+                        np.finfo(np.float32).tiny], np.float32)
+    table = jnp.asarray(np.resize(special, (24, 128))
+                        * np.arange(1, 25, dtype=np.float32)[:, None]
+                        ).astype(dtype)
+    table = table.at[::3].set(-0.0)
+    idx = jnp.asarray(np.arange(23, -1, -1).astype(np.int32))
+    got = np.asarray(spec_gather(table, idx, block_d=128))
+    want = np.asarray(table)[np.asarray(idx)]
+    bits = np.uint16 if dtype == jnp.bfloat16 else np.uint32
+    np.testing.assert_array_equal(got.view(bits), want.view(bits))
 
 
 def test_spec_gather_all_poisoned():

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import base
-from repro.launch.mesh import auto_axis_types
 from repro.models import moe
 from repro.models.model import build_model
 from repro.resilience import faults
@@ -47,8 +46,11 @@ def _x(n: int = 64, key: int = 1):
                              jnp.float32)
 
 
+AUTO2 = (jax.sharding.AxisType.Auto,) * 2
+
+
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"), **auto_axis_types(2))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
 
 
 # ---------------------------------------------------------------------------
@@ -79,7 +81,8 @@ def test_spec_kernel_bitexact_ep_mesh(cf):
     p, x = _moe_params(), _x()
     kw = dict(n_experts=CFG.n_experts, top_k=CFG.top_k, capacity_factor=cf)
     flat, pois_flat = moe._moe_spec_flat(p, x, stats=True, **kw)
-    with _mesh11() as mesh:
+    mesh = _mesh11()
+    with jax.set_mesh(mesh):
         ref, pois_ref = moe._moe_spec_ep(p, x, mesh=mesh, stats=True, **kw)
         ker, pois_ker = moe._moe_spec_ep(p, x, mesh=mesh, kernel=True,
                                          stats=True, **kw)
@@ -96,7 +99,8 @@ def test_spec_kernel_bitexact_tp_mesh(cf):
     p, x = _moe_params(), _x()
     kw = dict(n_experts=CFG.n_experts, top_k=CFG.top_k, capacity_factor=cf)
     _, pois_flat = moe._moe_spec_flat(p, x, stats=True, **kw)
-    with _mesh11() as mesh:
+    mesh = _mesh11()
+    with jax.set_mesh(mesh):
         ref, pois_ref = moe._moe_spec_tp(p, x, mesh=mesh, stats=True, **kw)
         ker, pois_ker = moe._moe_spec_tp(p, x, mesh=mesh, kernel=True,
                                          stats=True, **kw)
@@ -112,8 +116,8 @@ def test_spec_kernel_bitexact_ep_multidevice():
     p, x = _moe_params(), _x()
     kw = dict(n_experts=CFG.n_experts, top_k=CFG.top_k, capacity_factor=0.5)
     _, pois_flat = moe._moe_spec_flat(p, x, stats=True, **kw)
-    mesh = jax.make_mesh((1, 2), ("data", "model"), **auto_axis_types(2))
-    with mesh:
+    mesh = jax.make_mesh((1, 2), ("data", "model"), axis_types=AUTO2)
+    with jax.set_mesh(mesh):
         ref, pois_ref = moe._moe_spec_ep(p, x, mesh=mesh, stats=True, **kw)
         ker, pois_ker = moe._moe_spec_ep(p, x, mesh=mesh, kernel=True,
                                          stats=True, **kw)
@@ -128,7 +132,7 @@ def test_moe_spec_routes_to_ep_under_mesh():
     model-axis mesh and still honors kernel/stats."""
     p, x = _moe_params(), _x()
     kw = dict(n_experts=CFG.n_experts, top_k=CFG.top_k, capacity_factor=1.25)
-    with _mesh11():
+    with jax.set_mesh(_mesh11()):
         out, pois = moe.moe_spec(p, x, kernel=True, stats=True, **kw)
     ref = moe._moe_spec_flat(p, x, **kw)
     assert out.shape == ref.shape

@@ -67,8 +67,8 @@ def test_dryrun_cell_small_mesh(tmp_path):
     from repro.launch.hlo_cost import analyze_hlo
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.launch.mesh import auto_axis_types
-    mesh = jax.make_mesh((2, 2), ("data", "model"), **auto_axis_types(2))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     cfg = smoke(get("phi4_mini_3_8b"))
     model = build_model(cfg)
     from repro.train.train_step import make_train_step
@@ -77,7 +77,7 @@ def test_dryrun_cell_small_mesh(tmp_path):
     sh = mesh_mod.shard_pytree_specs(shapes, cfg, mesh, fsdp=True)
     batch = {"tokens": jax.ShapeDtypeStruct((8, 32), jnp.int32)}
     bsh = {"tokens": NamedSharding(mesh, P("data", None))}
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(train_step, in_shardings=(sh, bsh),
                           out_shardings=(sh, None)).lower(shapes, batch)
         compiled = lowered.compile()
