@@ -30,6 +30,16 @@ Fault sites (armed :class:`~repro.resilience.faults.FaultPlan` only):
 ``serve.decode`` (a decode step times out, killing the wave with no
 culprit), ``serve.storm`` (the queue doubles mid-run with synthetic
 clones — shed after serving, excluded from results).
+
+Spans (``jax.profiler.TraceAnnotation``, on the profiler's clock; about
+a microsecond each with the profiler off): ``engine.wave`` wraps a whole
+wave (its request ids, space-separated, as metadata ``rids``); inside it
+``engine.prefill`` (padding, upload, prefill, its poison read, the first
+argmax) and one ``engine.step`` per decode-loop iteration, which holds
+``engine.commit`` (the per-row token reads) and ``engine.decode`` (the
+jitted decode call, its poison read, the argmax).  Every blocking
+device-to-host read is an ``engine.sync`` span inside one of those
+(:func:`_sync`).
 """
 from __future__ import annotations
 
@@ -41,6 +51,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs.base import ArchConfig
 from ..models.model import build_model, group_count, group_pattern
@@ -71,6 +82,13 @@ class WaveStats:
     moe_poison: int      # poisoned MoE dispatch requests (capacity races)
     moe_requests: int    # total MoE dispatch requests issued
     truncated: int       # requests cut off at max_len this wave
+
+
+def _sync(a, *index) -> int:
+    """One blocking device-to-host read of ``a[index]`` (of ``a`` itself
+    without an index), as an ``engine.sync`` span."""
+    with TraceAnnotation("engine.sync"):
+        return int(a[index] if index else a)
 
 
 class Engine:
@@ -133,7 +151,10 @@ class Engine:
         failed, survivors are pushed back onto ``queue``, and None is
         returned — a torn wave never commits and never produces stats."""
         try:
-            stats = self._run_wave(wave)
+            # the profiler's metadata format splits values at commas
+            with TraceAnnotation("engine.wave",
+                                 rids=" ".join(str(r.rid) for r in wave)):
+                stats = self._run_wave(wave)
         except Exception as e:  # noqa: BLE001 — degrade, don't crash
             rid = getattr(e, "rid", None)
             site = getattr(e, "site", "")
@@ -174,52 +195,57 @@ class Engine:
                         rid=r.rid)
         b = len(wave)
         t0 = time.perf_counter()
-        plen = max(len(r.prompt) for r in wave)
-        toks = np.zeros((b, plen), np.int32)
-        pads = np.zeros((b,), np.int32)
-        for i, r in enumerate(wave):
-            toks[i, plen - len(r.prompt):] = r.prompt  # left-pad
-            pads[i] = plen - len(r.prompt)
-        # pad slots are poisoned requests, not token 0: pad_lens masks them
-        # out of attention and re-bases RoPE, so a batched request decodes
-        # exactly what its solo run would
-        pad_lens = jnp.asarray(pads)
-        logits, cache, pstats = self.model.prefill(
-            self.params, jnp.asarray(toks), max_len=self.max_len,
-            pad_lens=pad_lens, return_stats=True)
-        poison = int(pstats["moe_poison"])
-        moe_reqs = b * plen * self._moe_per_tok
-        pos = plen
-        cur = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+        with TraceAnnotation("engine.prefill"):
+            plen = max(len(r.prompt) for r in wave)
+            toks = np.zeros((b, plen), np.int32)
+            pads = np.zeros((b,), np.int32)
+            for i, r in enumerate(wave):
+                toks[i, plen - len(r.prompt):] = r.prompt  # left-pad
+                pads[i] = plen - len(r.prompt)
+            # pad slots are poisoned requests, not token 0: pad_lens masks
+            # them out of attention and re-bases RoPE, so a batched request
+            # decodes exactly what its solo run would
+            pad_lens = jnp.asarray(pads)
+            logits, cache, pstats = self.model.prefill(
+                self.params, jnp.asarray(toks), max_len=self.max_len,
+                pad_lens=pad_lens, return_stats=True)
+            poison = _sync(pstats["moe_poison"])
+            moe_reqs = b * plen * self._moe_per_tok
+            pos = plen
+            cur = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
         max_new = max(r.max_new for r in wave)
         tokens = 0
         for step in range(max_new):
-            faults.inject("serve.decode")
-            for i, r in enumerate(wave):
-                if step < r.max_new:
-                    r.out.append(int(cur[i, 0]))
-                    tokens += 1
-            if pos + 1 >= self.max_len:
-                if step + 1 < max_new:
-                    # out of cache, output budget remaining: an explicit
-                    # degradation event, never a silent cut
-                    for r in wave:
-                        if step + 1 < r.max_new:
-                            r.truncated = True
-                            self.events.append(FailureEvent(
-                                site="serve.truncate", rung="request",
-                                cause=(f"request {r.rid} hit max_len="
-                                       f"{self.max_len} with "
-                                       f"{r.max_new - step - 1} tokens "
-                                       "unserved"),
-                                retries=r.retries, outcome="truncated"))
-                break
-            logits, cache, dstats = self._decode(self.params, cache, cur,
-                                                 pos, pad_lens)
-            poison += int(dstats["moe_poison"])
-            moe_reqs += b * self._moe_per_tok
-            cur = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-            pos += 1
+            with TraceAnnotation("engine.step"):
+                faults.inject("serve.decode")
+                with TraceAnnotation("engine.commit"):
+                    for i, r in enumerate(wave):
+                        if step < r.max_new:
+                            r.out.append(_sync(cur, i, 0))
+                            tokens += 1
+                if pos + 1 >= self.max_len:
+                    if step + 1 < max_new:
+                        # out of cache, output budget remaining: an
+                        # explicit degradation event, never a silent cut
+                        for r in wave:
+                            if step + 1 < r.max_new:
+                                r.truncated = True
+                                self.events.append(FailureEvent(
+                                    site="serve.truncate", rung="request",
+                                    cause=(f"request {r.rid} hit max_len="
+                                           f"{self.max_len} with "
+                                           f"{r.max_new - step - 1} tokens "
+                                           "unserved"),
+                                    retries=r.retries, outcome="truncated"))
+                    break
+                with TraceAnnotation("engine.decode"):
+                    logits, cache, dstats = self._decode(
+                        self.params, cache, cur, pos, pad_lens)
+                    poison += _sync(dstats["moe_poison"])
+                    moe_reqs += b * self._moe_per_tok
+                    cur = jnp.argmax(logits, axis=-1)[:, None].astype(
+                        jnp.int32)
+                pos += 1
         jax.block_until_ready(logits)
         for r in wave:
             r.done = True

@@ -66,6 +66,17 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _assert_kernel_named(compiled, kernel):
+    """Every Pallas kernel of ``compiled`` keeps ``kernel`` in the name a
+    profiler trace gives it (its HLO line, read by
+    ``bench/lib/trace.op_name``), which the roofline readers match."""
+    from bench.lib.trace import op_name
+    names = [op_name(line.strip().removeprefix("ROOT "))
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert names and all(kernel in n for n in names), names
+
+
 def _moe_capacity():
     from repro.models.moe import round_capacity
     return round_capacity(TOKENS, N_EXPERTS, TOP_K, 1.25)
@@ -90,9 +101,11 @@ def test_spec_gather_compiles(one_chip, case):
     shape, dtype, n, bd = KERNEL_SHAPES[case]
     fn = functools.partial(_spec_gather, block_d=bd, block_n=8,
                            interpret=False)
-    _assert_kernel(jax.jit(fn).lower(
+    compiled = jax.jit(fn).lower(
         _spec(shape(), dtype, one_chip),
-        _spec((n,), jnp.int32, one_chip)).compile())
+        _spec((n,), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+    _assert_kernel_named(compiled, "spec_gather")
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_SHAPES))
@@ -101,10 +114,12 @@ def test_spec_scatter_add_compiles(one_chip, case):
     shape, dtype, n, bd = KERNEL_SHAPES[case]
     fn = functools.partial(_spec_scatter_add, block_d=bd, block_n=8,
                            interpret=False)
-    _assert_kernel(jax.jit(fn).lower(
+    compiled = jax.jit(fn).lower(
         _spec(shape(), dtype, one_chip),
         _spec((n,), jnp.int32, one_chip),
-        _spec((n, shape()[1]), dtype, one_chip)).compile())
+        _spec((n, shape()[1]), dtype, one_chip)).compile()
+    _assert_kernel(compiled)
+    _assert_kernel_named(compiled, "spec_scatter_add")
 
 
 def _moe_params(dtype, n_experts, expert, other):
