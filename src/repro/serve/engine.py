@@ -36,10 +36,13 @@ a microsecond each with the profiler off): ``engine.wave`` wraps a whole
 wave (its request ids, space-separated, as metadata ``rids``); inside it
 ``engine.prefill`` (padding, upload, prefill, its poison read, the first
 argmax) and one ``engine.step`` per decode-loop iteration, which holds
-``engine.commit`` (the per-row token reads) and ``engine.decode`` (the
-jitted decode call, its poison read, the argmax).  Every blocking
-device-to-host read is an ``engine.sync`` span inside one of those
-(:func:`_sync`).
+``engine.commit`` (one read of the step's whole token array, then the
+per-row appends from that host copy) and ``engine.decode`` (the jitted
+decode call, which adds its poison count to a device-side total, and the
+argmax).  Every blocking device-to-host read is an ``engine.sync`` span
+(:func:`_sync`): one in the prefill, one in each step's commit, and one
+after the last step, inside ``engine.wave``, that reads the wave's decode
+poison total and so waits for the last decode call.
 """
 from __future__ import annotations
 
@@ -84,11 +87,11 @@ class WaveStats:
     truncated: int       # requests cut off at max_len this wave
 
 
-def _sync(a, *index) -> int:
-    """One blocking device-to-host read of ``a[index]`` (of ``a`` itself
-    without an index), as an ``engine.sync`` span."""
+def _sync(a) -> np.ndarray:
+    """One blocking device-to-host read of ``a``, as an ``engine.sync``
+    span."""
     with TraceAnnotation("engine.sync"):
-        return int(a[index] if index else a)
+        return np.asarray(a)
 
 
 class Engine:
@@ -209,19 +212,23 @@ class Engine:
             logits, cache, pstats = self.model.prefill(
                 self.params, jnp.asarray(toks), max_len=self.max_len,
                 pad_lens=pad_lens, return_stats=True)
-            poison = _sync(pstats["moe_poison"])
+            poison = int(_sync(pstats["moe_poison"]))
             moe_reqs = b * plen * self._moe_per_tok
             pos = plen
             cur = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+        # the decode calls' poison counts stay on the device until the
+        # wave's end, so a step blocks on its tokens alone
+        decode_poison = jnp.zeros((), jnp.int32)
         max_new = max(r.max_new for r in wave)
         tokens = 0
         for step in range(max_new):
             with TraceAnnotation("engine.step"):
                 faults.inject("serve.decode")
                 with TraceAnnotation("engine.commit"):
+                    host = _sync(cur)
                     for i, r in enumerate(wave):
                         if step < r.max_new:
-                            r.out.append(_sync(cur, i, 0))
+                            r.out.append(int(host[i, 0]))
                             tokens += 1
                 if pos + 1 >= self.max_len:
                     if step + 1 < max_new:
@@ -241,12 +248,12 @@ class Engine:
                 with TraceAnnotation("engine.decode"):
                     logits, cache, dstats = self._decode(
                         self.params, cache, cur, pos, pad_lens)
-                    poison += _sync(dstats["moe_poison"])
+                    decode_poison = decode_poison + dstats["moe_poison"]
                     moe_reqs += b * self._moe_per_tok
                     cur = jnp.argmax(logits, axis=-1)[:, None].astype(
                         jnp.int32)
                 pos += 1
-        jax.block_until_ready(logits)
+        poison += int(_sync(decode_poison))
         for r in wave:
             r.done = True
         return WaveStats(batch=b, wall_s=time.perf_counter() - t0,

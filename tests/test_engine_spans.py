@@ -3,7 +3,8 @@
 One tiny MoE wave is served with the profiler off, then again under
 ``jax.profiler``; the ``.xplane.pb`` is read with
 ``jax.profiler.ProfileData``.  The spans must nest as documented in
-``repro.serve.engine``, count one ``engine.sync`` per blocking read, and
+``repro.serve.engine``, count one ``engine.sync`` per blocking read (one
+in the prefill, one in each step's commit, one after the last step), and
 leave the served tokens as they were.
 """
 from __future__ import annotations
@@ -103,12 +104,16 @@ def test_spans_of_one_wave(case, tmp_path):
         decode = [d for d in by["engine.decode"] if _inside(d, [step])]
         assert len(commit) == 1
         assert len(decode) == (0 if truncated and k == steps - 1 else 1)
+        # one read of the step's whole token array, inside its commit
         syncs = [s for s in by["engine.sync"] if _inside(s, [step])]
-        active = sum(k < m for m in max_new)
-        assert len(syncs) == active + len(decode)
-        assert len([s for s in syncs if _inside(s, commit)]) == active
-        assert len([s for s in syncs if _inside(s, decode)]) == len(decode)
-    # the prefill's poison read is its one sync; none lies outside a parent
+        assert len(syncs) == 1 and _inside(syncs[0], commit)
+    assert not [s for s in by["engine.sync"]
+                if _inside(s, by["engine.decode"])]
+    # the prefill's poison read is its one sync
     assert len([s for s in by["engine.sync"] if _inside(s, prefill)]) == 1
-    parents = prefill + by["engine.commit"] + by["engine.decode"]
-    assert all(_inside(s, parents) for s in by["engine.sync"])
+    # the wave's decode poison total: one read after the last step
+    last = by["engine.step"][-1]
+    after = [s for s in by["engine.sync"] if s[1] >= last[2]]
+    assert len(after) == 1 and _inside(after[0], wave)
+    # and no other: the prefill's, one a step, the wave's
+    assert len(by["engine.sync"]) == 1 + steps + 1

@@ -17,6 +17,8 @@ Three concerns, one file:
 """
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -246,6 +248,51 @@ def test_wave_stats_accounting():
     assert st.moe_requests == (2 * 4 + 3 * 2) * per_tok
     assert 0 <= st.moe_poison <= st.moe_requests
     assert st.wall_s > 0
+
+
+def test_served_wave_matches_hand_stepped_loop():
+    """A served wave commits exactly the tokens, and counts exactly the
+    poisoned dispatches, of a hand-stepped loop: prefill, then one decode
+    call per step, the last step's included.  The engine reads each step's
+    tokens in one transfer and the decode poison total once, at the
+    wave's end; neither may change what it serves or counts."""
+    # an expert holds at least 8 dispatches, so a decode step loses some
+    # only with more rows than that routed to one of the 4 experts
+    cfg = dataclasses.replace(CFG, capacity_factor=0.5)
+    rows = 16
+    eng = Engine(cfg, slots=rows, max_len=32, dispatch="spec-kernel")
+    prompts = _prompts([4 + i % 5 for i in range(rows)], cfg.vocab, seed=4)
+    max_new = [1 + i % 4 for i in range(rows)]
+    reqs = [Request(rid=i, prompt=p, max_new=m)
+            for i, (p, m) in enumerate(zip(prompts, max_new))]
+    eng.run(reqs)
+    (st,) = eng.wave_stats
+
+    model, plen = eng.model, max(map(len, prompts))
+    toks = np.zeros((len(prompts), plen), np.int32)
+    pads = np.zeros((len(prompts),), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, plen - len(p):] = p
+        pads[i] = plen - len(p)
+    pads = jnp.asarray(pads)
+    logits, cache, stats = model.prefill(eng.params, jnp.asarray(toks), 32,
+                                         pad_lens=pads, return_stats=True)
+    prefill_poison, poison = int(stats["moe_poison"]), 0
+    decode = jax.jit(lambda p, c, t, n, pl: model.decode_step(
+        p, c, t, n, pad_lens=pl, return_stats=True))
+    want = [[] for _ in prompts]
+    for step in range(max(max_new)):
+        cur = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+        for i, m in enumerate(max_new):
+            if step < m:
+                want[i].append(int(cur[i, 0]))
+        logits, cache, stats = decode(eng.params, cache, cur, plen + step,
+                                      pads)
+        poison += int(stats["moe_poison"])
+    assert [r.out for r in reqs] == want
+    assert st.tokens == sum(max_new)
+    assert prefill_poison > 0 and poison > 0
+    assert st.moe_poison == prefill_poison + poison
 
 
 def test_traffic_report():
