@@ -1,9 +1,14 @@
-"""Plain reference of the ``kimi_k2_ep24`` configuration, and its weights.
+"""Plain reference of the ``kimi_k2_ep24`` configuration, its weights,
+and the work it states.
 
-Imports nothing of the program.  :func:`make_params` draws the weights
-from the seed on the device, in one jitted call, in the dtype they are
-served in, laid out as the serving program takes them (one stacked group
-per layer: an attention sublayer, then an MoE sublayer).
+Imports nothing of the program.  :func:`program_fields` says which of the
+program's ``ArchConfig`` fields the file sets, and refuses what the
+program does not run; :func:`kernel_calls` and :func:`token_flops` count
+a wave's speculative kernel calls and one token's operations from the
+file's widths (``bench/lib/work.py``).  :func:`make_params` draws the
+weights from the seed on the device, in one jitted call, in the dtype
+they are served in, laid out as the serving program takes them (one
+stacked group per layer: an attention sublayer, then an MoE sublayer).
 
 :func:`final_hidden` is the model's forward pass in float32 at the
 ``highest`` matmul precision, straight from the semantics the
@@ -35,7 +40,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.lib import work
+
 F8 = jnp.float8_e4m3fn
+
+#: what the serving program runs for each key it does not map; a file
+#: that states another value is refused before any weight is drawn
+PROGRAM_RUNS = {"first_k_dense_replace": 0, "scoring_func": "softmax",
+                "norm_topk_prob": False, "routed_scaling_factor": 1.0,
+                "hidden_act": "silu"}
 
 
 def sizes(cfg: Dict) -> Dict:
@@ -119,6 +132,68 @@ def capacity(n_tokens: int, experts: int, top_k: int, cf: float) -> int:
     plus one, rounded up to a multiple of 8 (at least 8)."""
     cap = int(cf * n_tokens * top_k / experts) + 1
     return max(8, -(-cap // 8) * 8)
+
+
+def program_fields(cfg: Dict) -> Dict:
+    """The fields of the program's ``ArchConfig`` that the file sets, over
+    the program's own config named by ``assumed.program_config``.  Raises
+    ``ValueError``, naming the key and the value stated, where the file
+    states what the program does not run."""
+    a = cfg["assumed"]
+    if a.get("attention") != "gqa":
+        raise ValueError(f"config states assumed.attention="
+                         f"{a.get('attention')!r}; the serving program runs "
+                         "'gqa' only")
+    for k, v in PROGRAM_RUNS.items():
+        if cfg[k] != v:
+            raise ValueError(f"config states {k}={cfg[k]!r}; the serving "
+                             f"program runs {v!r} only")
+    rope = cfg["rope_scaling"]
+    if rope is not None and (rope["type"], rope["factor"]) != ("yarn", 1):
+        raise ValueError(f"config states rope_scaling={rope!r}; the serving "
+                         "program runs unscaled RoPE only (YaRN at factor 1)")
+    return dict(
+        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], vocab=cfg["vocab_size"],
+        n_experts=cfg["n_routed_experts"], top_k=cfg["num_experts_per_tok"],
+        moe_d_ff=cfg["moe_intermediate_size"],
+        n_shared_experts=cfg["n_shared_experts"],
+        rope_theta=float(cfg["rope_theta"]), head_dim=a["head_dim"],
+        capacity_factor=a["capacity_factor"], dtype=a["dtype"])
+
+
+def kernel_calls(cfg: Dict, rows: int, plen: int,
+                 decode_calls: int) -> List[Dict]:
+    """The speculative kernels' calls of one wave of ``rows`` requests
+    padded to ``plen`` prompt tokens, with their shapes: every layer is an
+    MoE layer, and dispatches once over the prefill's ``rows * plen``
+    tokens and once over the ``rows`` tokens of each decode call."""
+    s = sizes(cfg)
+    itemsize = jnp.dtype(s["dtype"]).itemsize
+    calls = []
+    for tokens, count in ((rows * plen, s["layers"]),
+                          (rows, s["layers"] * decode_calls)):
+        calls += work.spec_dispatch(
+            tokens, count, d=s["d"], experts=s["experts"],
+            top_k=s["top_k"], itemsize=itemsize,
+            capacity=capacity(tokens, s["experts"], s["top_k"], s["cf"]))
+    return calls
+
+
+def token_flops(cfg: Dict, context: int, head: bool) -> float:
+    """Operations of one token's forward pass attending to ``context``
+    positions: every layer's attention and MoE (the router, the top-k
+    routed experts and the shared ones), and the output head where the
+    token's logits are needed."""
+    s = sizes(cfg)
+    layer = (work.gqa_macs(s["d"], s["heads"], s["kv"], s["hd"], context)
+             + work.moe_macs(s["d"], s["experts"], s["ff"],
+                             s["top_k"] + s["shared"]))
+    macs = s["layers"] * layer
+    if head:
+        macs += work.head_macs(s["d"], s["vocab"])
+    return 2.0 * macs
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,11 @@ where the wave's cache is full and no call follows it, is stamped when
 the wave returns.  Every wave's stamps must equal its committed tokens,
 so a change to the engine's loop fails the run instead of mis-timing it.
 
+What the program runs, the kernel calls a wave makes and the operations
+a token needs are the configuration's to state: its module
+(``bench/configs/<config>.py``) holds the functions of ``CONFIG_NEEDS``,
+and this driver knows no model.
+
 Set-up draws the weights, builds the engine and serves one short wave at
 each padded prompt length the traffic's cycle holds, so the window
 compiles nothing.  Once the window has closed, one wave served in it (the
@@ -36,12 +41,17 @@ import numpy as np
 
 from bench.lib import chip, stats
 from bench.lib.outcome import Check, Context, Outcome
-from bench.lib.spec import load_module
 from bench.lib.traffic import ClosedLoop
 
 
 #: the numbers compared, each with a limit in ``bench/limits``
 GAP_CHECKS = {"mean_logit_gap": "mean", "worst_row_logit_gap": "worst_row"}
+
+#: what a serving configuration's module holds: the program's config
+#: fields, the weights, the wave's kernel calls, one token's operations
+#: (read by ``model_step.mfu``) and the plain reference
+CONFIG_NEEDS = ("program_fields", "make_params", "kernel_calls",
+                "token_flops", "final_hidden", "head")
 
 
 class StampMismatch(RuntimeError):
@@ -52,32 +62,14 @@ class TooFewSamples(RuntimeError):
     """The window holds fewer samples than the mix needs for its tails."""
 
 
-def program_config(cfg: Dict):
-    """The program's ``ArchConfig`` for a configuration file; refuses a
-    file that states what the program cannot run."""
+def program_arch(cfg: Dict, ref):
+    """The program's ``ArchConfig`` for a configuration file: the program's
+    own config named by ``assumed.program_config``, with the fields that
+    the configuration's module sets (it refuses what the program does not
+    run)."""
     from repro.configs.base import get
-    a = cfg["assumed"]
-    need = {"first_k_dense_replace": 0, "scoring_func": "softmax",
-            "norm_topk_prob": False, "routed_scaling_factor": 1.0,
-            "hidden_act": "silu"}
-    for k, v in need.items():
-        if cfg[k] != v:
-            raise ValueError(f"config states {k}={cfg[k]!r}; the serving "
-                             f"program runs {v!r} only")
-    rope = cfg["rope_scaling"]
-    if rope is not None and (rope["type"], rope["factor"]) != ("yarn", 1):
-        raise ValueError(f"config states rope_scaling={rope!r}; the serving "
-                         "program runs unscaled RoPE only (YaRN at factor 1)")
-    base = get(a["program_config"])
-    return dataclasses.replace(
-        base, n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], vocab=cfg["vocab_size"],
-        n_experts=cfg["n_routed_experts"], top_k=cfg["num_experts_per_tok"],
-        moe_d_ff=cfg["moe_intermediate_size"],
-        n_shared_experts=cfg["n_shared_experts"],
-        rope_theta=float(cfg["rope_theta"]), head_dim=a["head_dim"],
-        capacity_factor=a["capacity_factor"], dtype=a["dtype"])
+    return dataclasses.replace(get(cfg["assumed"]["program_config"]),
+                               **ref.program_fields(cfg))
 
 
 def check_layout(arch, params) -> None:
@@ -136,26 +128,6 @@ class Stamps:
                                 f"committed {committed}")
 
 
-def _kernel_calls(arch, capacity, b: int, plen: int,
-                  decode_calls: int) -> List[Dict]:
-    """The speculative kernels' calls of one wave, with their shapes:
-    one gather and one scatter per MoE layer per engine call."""
-    moe_layers = arch.n_layers
-    calls = []
-    for n_tok, count in ((b * plen, moe_layers),
-                         (b, moe_layers * decode_calls)):
-        if not count:
-            continue
-        cap = capacity(n_tok, arch.n_experts, arch.top_k,
-                       arch.capacity_factor)
-        shape = dict(requests=n_tok * arch.top_k, d=arch.d_model,
-                     itemsize=2 if arch.dtype == "bfloat16" else 4,
-                     table_rows=arch.n_experts * cap)
-        calls += [dict(kernel="spec_gather", count=count, **shape),
-                  dict(kernel="spec_scatter_add", count=count, **shape)]
-    return calls
-
-
 def run(ctx: Context) -> Outcome:
     import jax
     from jax.profiler import TraceAnnotation
@@ -163,8 +135,8 @@ def run(ctx: Context) -> Outcome:
     from repro.serve.engine import Engine, Request
 
     cell, cfg = ctx.cell, ctx.cell.config
-    ref = load_module("configs", cell.config_name)
-    arch = program_config(cfg)
+    ref = cell.module()
+    arch = program_arch(cfg, ref)
     params = jax.block_until_ready(ref.make_params(cfg, ctx.seed))
     check_layout(arch, params)
     loop = ClosedLoop(cell.traffic, ctx.seed, arch.vocab)
@@ -213,8 +185,8 @@ def run(ctx: Context) -> Outcome:
                 fed=stamps.fed,
                 prompts=[q.prompt for q in batch],
                 out=[list(r.out) for r in reqs],
-                kernel_calls=_kernel_calls(arch, ref.capacity, len(reqs),
-                                           plen, len(stamps.calls)),
+                kernel_calls=ref.kernel_calls(cfg, len(reqs), plen,
+                                              len(stamps.calls)),
                 poison=None if st is None else int(st.moe_poison),
                 failed=st is None))
             for r, ts in zip(reqs, stamps.times):

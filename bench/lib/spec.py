@@ -2,12 +2,14 @@
 ``BENCHMARK.json``.
 
 A cell names a configuration and a traffic mix; the configuration's file
-says which driver (``kind``) runs it.  Per-layer metrics are readers in
-``bench/metrics/<name>.py``, kernel costs are ``bench/costs/<kernel>.py``,
-correctness limits are ``bench/limits/<workload>.json`` and the chip's
-peaks are ``bench/peaks.json``, keyed by the device kind JAX reports.  A
-name with no file, or a device with no peaks, is an error, never a
-default.
+says which driver (``kind``) runs it, and the configuration's module
+(``bench/configs/<config>.py``) holds its plain reference and the
+functions that driver names in ``CONFIG_NEEDS``.  Per-layer metrics are
+readers in ``bench/metrics/<name>.py``, kernel costs are
+``bench/costs/<kernel>.py``, correctness limits are
+``bench/limits/<workload>.json`` and the chip's peaks are
+``bench/peaks.json``, keyed by the device kind JAX reports.  A name with
+no file, or a device with no peaks, is an error, never a default.
 """
 from __future__ import annotations
 
@@ -80,6 +82,10 @@ class Cell:
     def limits(self) -> Dict[str, Any]:
         return load_json(_file("limits", self.name, ".json"))
 
+    def module(self):
+        """The configuration's module, ``bench/configs/<config>.py``."""
+        return load_module("configs", self.config_name)
+
 
 def _metrics(entries, cell: str, end_to_end: bool) -> List[Metric]:
     out = []
@@ -118,7 +124,13 @@ def load_cell(workload: str, bench_json: str | None = None) -> Cell:
                 _metrics(spec["per_layer"], workload, False))
     for m in cell.per_layer:       # every reader must exist before a run
         _file("metrics", m.name, ".py")
-    _file("drivers", cell.kind, ".py")
+    needs = getattr(load_module("drivers", cell.kind), "CONFIG_NEEDS", ())
+    module = cell.module() if needs else None
+    missing = [f for f in needs if not hasattr(module, f)]
+    if missing:
+        raise SpecError(f"configuration module of {w['config']!r} has no "
+                        f"{', '.join(missing)}; the {cell.kind} driver "
+                        "calls them")
     return cell
 
 

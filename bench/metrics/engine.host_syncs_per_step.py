@@ -1,7 +1,8 @@
 """Blocking device-to-host reads per decode step: the ``engine.sync``
 spans inside ``engine.step`` spans over the ``engine.step`` spans of the
-traced window (the engine's own spans, ``bench/lib/spans.py``).  Today a
-step reads each active row's token and the decode call's poison count."""
+traced window (the engine's own spans, ``bench/lib/spans.py``).  A step
+reads its whole token array once; the decode calls' poison counts stay on
+the device until one read after the wave's last step, outside any step."""
 from bench.lib import spans
 
 SOURCE = "program_span"

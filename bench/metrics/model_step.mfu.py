@@ -2,13 +2,14 @@
 forward pass needs for every token processed inside the window (each
 prompt token of a request whose first token came in the window, and each
 later token committed in it), over the window and the peak.  Operations
-per token come from ``bench/costs/model_step.py``."""
+per token come from the configuration's own ``token_flops``
+(``bench/configs/<config>.py``)."""
 SOURCE = "host_clock"
 UNIT = "%"
 
 
 def read(run):
-    cost = run.cost("model_step")
+    cost = run.cell.module().token_flops
     cfg = run.record["config"]
     lo, hi = run.record["window"]
     flops = 0.0

@@ -108,13 +108,67 @@ def test_kimi_config_keeps_every_published_width():
     assert rope["mscale_all_dim"] == published["mscale_all_dim"]
 
 
+def _kimi():
+    return json.load(open(os.path.join(spec.BENCH, "configs",
+                                       "kimi_k2_ep24.json")))
+
+
 @pytest.mark.parametrize("kind", ["drivers", "configs"])
 def test_scaled_rope_is_refused(kind):
-    cfg = json.load(open(os.path.join(spec.BENCH, "configs",
-                                      "kimi_k2_ep24.json")))
+    """Refused by the program's fields the driver builds on, and by the
+    reference."""
+    cfg = _kimi()
     cfg["rope_scaling"] = cfg["published"]["rope_scaling"]
-    mod = spec.load_module(kind, "serve" if kind == "drivers"
-                           else "kimi_k2_ep24")
-    read = mod.program_config if kind == "drivers" else mod.sizes
+    mod = spec.load_module("configs", "kimi_k2_ep24")
+    read = mod.program_fields if kind == "drivers" else mod.sizes
     with pytest.raises(ValueError, match="unscaled RoPE"):
         read(cfg)
+
+
+@pytest.mark.parametrize("key,value", [
+    ("first_k_dense_replace", 1), ("scoring_func", "sigmoid"),
+    ("norm_topk_prob", True), ("routed_scaling_factor", 2.827),
+    ("hidden_act", "gelu"), ("attention", None), ("attention", "mla")])
+def test_what_the_program_does_not_run_is_refused(key, value):
+    cfg = _kimi()
+    where = cfg["assumed"] if key == "attention" else cfg
+    if value is None:
+        del where[key]
+    else:
+        where[key] = value
+    with pytest.raises(ValueError, match=key):
+        spec.load_module("configs", "kimi_k2_ep24").program_fields(cfg)
+
+
+def test_kimi_program_config_is_the_fixed_mapping():
+    """The ``ArchConfig`` the serve driver builds for Kimi K2 is, field by
+    field, the one it built before the configuration stated its fields."""
+    import dataclasses
+
+    from repro.configs.base import get
+    ref = spec.load_module("configs", "kimi_k2_ep24")
+    got = spec.load_module("drivers", "serve").program_arch(_kimi(), ref)
+    want = dataclasses.replace(
+        get("kimi_k2_1t_a32b"), n_layers=4, d_model=7168, n_heads=64,
+        n_kv_heads=8, vocab=20480, n_experts=16, top_k=8, moe_d_ff=2048,
+        n_shared_experts=1, rope_theta=10000.0, head_dim=112,
+        capacity_factor=1.25, dtype="bfloat16")
+    assert dataclasses.asdict(got) == dataclasses.asdict(want)
+    assert type(got.rope_theta) is float
+
+
+@pytest.mark.parametrize("missing", ["program_fields", "kernel_calls",
+                                     "token_flops"])
+def test_config_module_without_a_needed_function_fails(missing,
+                                                       monkeypatch):
+    load = spec.load_module
+
+    def without(kind, name):
+        mod = load(kind, name)
+        if kind == "configs":
+            mod.__dict__.pop(missing)
+        return mod
+
+    monkeypatch.setattr(spec, "load_module", without)
+    with pytest.raises(spec.SpecError, match=missing):
+        spec.load_cell(CELLS[0])

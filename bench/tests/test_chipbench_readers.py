@@ -10,12 +10,13 @@ from bench.lib import trace as T
 PEAKS = spec.peaks("TPU v5 lite")
 CFG = json.load(open(os.path.join(spec.BENCH, "configs",
                                   "kimi_k2_ep24.json")))
+CELL = spec.load_cell("kimi_k2_ep24.decode_heavy")
 
 
 class View:
     def __init__(self, record, reduced):
         self.record, self.reduced = record, reduced
-        self.peaks, self.cost = PEAKS, spec.cost
+        self.cell, self.peaks, self.cost = CELL, PEAKS, spec.cost
 
 
 def read(name, record, reduced=None):
@@ -50,7 +51,7 @@ def test_compiles_are_read_from_the_counter():
 
 
 def test_mfu_by_hand():
-    cost = spec.cost("model_step")
+    cost = CELL.module().token_flops
     flops = (sum(cost(CFG, c, False) for c in range(1, 5))
              + cost(CFG, 4, True) - cost(CFG, 4, False)
              + cost(CFG, 5, True) + cost(CFG, 6, True)
